@@ -27,6 +27,9 @@ def main() -> None:
     ap.add_argument("--only", default=None, help="comma-separated module list")
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     from benchmarks import (
         bench_al,
         bench_broker,

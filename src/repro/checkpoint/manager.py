@@ -26,14 +26,13 @@ from typing import Any
 import jax
 import numpy as np
 
-from repro.common.compat import tree_flatten_with_path
 from repro.common.exceptions import CheckpointError
 
 _SEP = "/"
 
 
 def _flatten_with_paths(tree: Any) -> list[tuple[str, Any]]:
-    flat = tree_flatten_with_path(tree)[0]
+    flat = jax.tree.flatten_with_path(tree)[0]
     out = []
     for path, leaf in flat:
         key = _SEP.join(_path_part(p) for p in path)

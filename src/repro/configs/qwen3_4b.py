@@ -1,4 +1,4 @@
-"""qwen3-4b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-8B]."""
+"""qwen3-4b [dense] — qk_norm, GQA [hf:Qwen/Qwen3-4B]."""
 from repro.models.config import ArchConfig
 
 CONFIG = ArchConfig(

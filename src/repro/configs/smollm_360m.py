@@ -1,4 +1,4 @@
-"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-135M].
+"""smollm-360m [dense] — llama-arch small [hf:HuggingFaceTB/SmolLM-360M].
 
 15 heads (non-128-aligned head count): attention weights replicate on the
 model axis (heads→None sharding fallback) — exercised deliberately.

@@ -3,11 +3,13 @@
 TPU adaptation of the flash algorithm: 3-D grid ``(batch·kv_heads·groups,
 q_blocks, kv_blocks)`` with the KV dimension innermost and *arbitrary*
 (sequential), so the online-softmax state (m, l, acc) lives in VMEM
-scratch across KV iterations.  Block shapes are MXU-aligned (block_q ×
-d_head and block_kv × d_head, d_head padded to ≥128 by the wrapper when
-needed).  Causal/window masking is done blockwise: fully-masked KV blocks
-are skipped with ``pl.when`` (no wasted MXU work — unlike the pure-jnp
-chunked reference, which computes the full rectangle).
+scratch across KV iterations.  Blocks are block_q × d_head and
+block_kv × d_head; d_head is never padded, so a block's last dim always
+equals the array's (64 for smollm-360m, 128 for qwen3-4b), and the
+sequence is padded up to a whole number of blocks.  Causal/window
+masking is done blockwise: fully-masked KV blocks are skipped with
+``pl.when`` (no wasted MXU work — unlike the pure-jnp chunked reference,
+which computes the full rectangle).
 
 Layout: inputs are pre-transposed to [BHg, S, D] (one row of heads per
 grid cell), where BHg enumerates (batch, kv_head, q_group); K/V use the
@@ -23,8 +25,6 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
-
-from repro.common.compat import tpu_compiler_params
 
 NEG_INF = -1e30
 
@@ -165,7 +165,7 @@ def flash_attention_pallas(
             pltpu.VMEM((block_q, 128), jnp.float32),
             pltpu.VMEM((block_q, d), jnp.float32),
         ],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary"),
         ),
         interpret=interpret,

@@ -17,13 +17,11 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from repro.common.compat import tpu_compiler_params
-
 
 def _ssd_kernel(
     x_ref,      # [1, L, P]
-    dt_ref,     # [1, L]
-    a_ref,      # [1, 1]    (per-head A, negative)
+    dt_ref,     # [1, 1, L]
+    a_ref,      # [1, 1, 1] (per-head A, negative)
     b_ref,      # [1, L, N]
     c_ref,      # [1, L, N]
     o_ref,      # [1, L, P]
@@ -38,19 +36,28 @@ def _ssd_kernel(
         state_scr[...] = jnp.zeros_like(state_scr)
 
     x = x_ref[0].astype(jnp.float32)            # [L, P]
-    dt = dt_ref[0].astype(jnp.float32)          # [L]
-    a = a_ref[0, 0].astype(jnp.float32)         # scalar
+    dt_row = dt_ref[0].astype(jnp.float32)      # [1, L]
+    a = a_ref[0].astype(jnp.float32)            # [1, 1]
     bb = b_ref[0].astype(jnp.float32)           # [L, N]
     cc = c_ref[0].astype(jnp.float32)           # [L, N]
     state = state_scr[...]                      # [P, N]
 
-    dta = dt * a                                # [L]
-    cum = jnp.cumsum(dta)                       # [L]
-    xdt = x * dt[:, None]                       # [L, P]
-    # intra-chunk: y[t] = Σ_{j<=t} exp(cum_t - cum_j) (c_t·b_j) xdt[j]
-    seg = cum[:, None] - cum[None, :]           # [L, L]
     t_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 0)
     j_idx = jax.lax.broadcasted_iota(jnp.int32, (chunk, chunk), 1)
+    # dt is needed both as a row and as a [L, 1] column; Mosaic has no
+    # cumsum and no lane→sublane reshape, so the column and the prefix
+    # sums are masked reductions over [L, L] (exact f32 adds)
+    dt = jnp.sum(jnp.where(t_idx == j_idx, dt_row, 0.0), axis=1, keepdims=True)
+    dta = dt * a                                # [L, 1]
+    cum = jnp.sum(
+        jnp.where(t_idx >= j_idx, dt_row * a, 0.0), axis=1, keepdims=True
+    )                                           # [L, 1]
+    cum_row = jnp.sum(
+        jnp.where(t_idx <= j_idx, dta, 0.0), axis=0, keepdims=True
+    )                                           # [1, L]
+    xdt = x * dt                                # [L, P]
+    # intra-chunk: y[t] = Σ_{j<=t} exp(cum_t - cum_j) (c_t·b_j) xdt[j]
+    seg = cum - cum_row                         # [L, L]
     lmat = jnp.where(t_idx >= j_idx, jnp.exp(seg), 0.0)
     cb = jax.lax.dot_general(
         cc, bb, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
@@ -63,11 +70,12 @@ def _ssd_kernel(
     cs = jax.lax.dot_general(
         cc, state, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
     )                                           # [L, P]
-    y = y + cs * jnp.exp(cum)[:, None]
+    y = y + cs * jnp.exp(cum)
     # state update: S·exp(cum_last) + Σ_j exp(cum_last - cum_j) xdt_jᵀ b_j
-    dend = jnp.exp(cum[-1] - cum)               # [L]
-    state_scr[...] = state * jnp.exp(cum[-1]) + jax.lax.dot_general(
-        xdt * dend[:, None], bb, (((0,), (0,)), ((), ())),
+    cum_last = cum[chunk - 1:, :]               # [1, 1]
+    dend = jnp.exp(cum_last - cum)              # [L, 1]
+    state_scr[...] = state * jnp.exp(cum_last) + jax.lax.dot_general(
+        xdt * dend, bb, (((0,), (0,)), ((), ())),
         preferred_element_type=jnp.float32,
     )
     o_ref[0] = y.astype(o_ref.dtype)
@@ -89,18 +97,20 @@ def ssd_pallas(
     assert s % chunk == 0
     n_chunks = s // chunk
     xt = jnp.moveaxis(x, 2, 1).reshape(b * h, s, p)
-    dtt = jnp.moveaxis(dt, 2, 1).reshape(b * h, s)
-    at = a.reshape(h, 1)
+    # dt and a get a unit middle axis so each block's last two dims are
+    # (1, chunk) / (1, 1): equal to the array's, as Mosaic requires
+    dtt = jnp.moveaxis(dt, 2, 1).reshape(b * h, 1, s)
+    at = a.reshape(h, 1, 1)
     grid = (b * h, n_chunks)
 
     def seq_map(bh, ci):
         return (bh, ci, 0)
 
     def dt_map(bh, ci):
-        return (bh, ci)
+        return (bh, 0, ci)
 
     def a_map(bh, ci):
-        return (bh % h, 0)
+        return (bh % h, 0, 0)
 
     def bc_map(bh, ci):
         return (bh // h, ci, 0)
@@ -110,15 +120,15 @@ def ssd_pallas(
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, chunk, p), seq_map),
-            pl.BlockSpec((1, chunk), dt_map),
-            pl.BlockSpec((1, 1), a_map),
+            pl.BlockSpec((1, 1, chunk), dt_map),
+            pl.BlockSpec((1, 1, 1), a_map),
             pl.BlockSpec((1, chunk, n), bc_map),
             pl.BlockSpec((1, chunk, n), bc_map),
         ],
         out_specs=pl.BlockSpec((1, chunk, p), seq_map),
         out_shape=jax.ShapeDtypeStruct((b * h, s, p), x.dtype),
         scratch_shapes=[pltpu.VMEM((p, n), jnp.float32)],
-        compiler_params=tpu_compiler_params(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
         ),
         interpret=interpret,

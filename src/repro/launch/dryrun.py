@@ -30,7 +30,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import NamedSharding, PartitionSpec as P
 
-from repro.common import compat
 from repro.configs import ARCH_IDS, get_config
 from repro.launch.analytic import cell_analytics
 from repro.launch.hlo_analysis import analyze_hlo
@@ -171,7 +170,7 @@ def lower_cell(
     compiled = lowered.compile()
     t_compile = time.time() - t0 - t_lower
 
-    cost = compat.cost_analysis(compiled)
+    cost = compiled.cost_analysis() or {}
     mem = compiled.memory_analysis()
     hlo = compiled.as_text()
     parsed = analyze_hlo(hlo)        # per-device, trip-count-aware

@@ -7,20 +7,30 @@ initialization and only then builds meshes.
 """
 from __future__ import annotations
 
+from typing import Sequence
+
 import jax
-from jax.sharding import Mesh
+from jax.sharding import AxisType, Mesh
+
+# The model code places its arrays with sharding constraints and lets
+# GSPMD propagate the rest, so every mesh axis is Auto (jax.make_mesh
+# defaults to Explicit axes).
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
-def make_host_mesh() -> Mesh:
-    """Whatever devices exist, as a 1×N (data, model) mesh — smoke tests."""
-    n = len(jax.devices())
-    return jax.make_mesh((1, n), ("data", "model"))
+def make_host_mesh(devices: Sequence[jax.Device] | None = None) -> Mesh:
+    """``devices`` (default: every local device) as a 1×N (data, model)
+    mesh."""
+    devices = list(devices if devices is not None else jax.devices())
+    return jax.make_mesh(
+        (1, len(devices)), ("data", "model"),
+        axis_types=(AxisType.Auto,) * 2, devices=devices,
+    )
 
 
 # TPU v5e hardware constants (per chip) — roofline denominators.

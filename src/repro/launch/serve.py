@@ -32,6 +32,9 @@ def main() -> None:
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
 
+    from repro.launch.cache import enable_compile_cache
+
+    enable_compile_cache()
     import jax
 
     from repro.models.lm import init_params_and_specs

@@ -1,9 +1,10 @@
-"""Training launcher.
+"""Training launcher: one process over every local device.
 
-Single-host: ``python -m repro.launch.train --arch smollm-360m --smoke
---steps 100``.  On a pod the same entry point builds the production mesh
-and shards the state with the logical rules (the dry-run proves those
-configurations compile; this driver is what a real deployment runs).
+``python -m repro.launch.train --arch smollm-360m --smoke --steps 100``.
+With one device the step is a plain jit.  With several, ``Trainer`` lays
+a 1×N (data, model) mesh over all of them (``launch.mesh.make_host_mesh``),
+builds the state in place with ``FSDP_RULES`` shardings, and jits the
+step with matching in and out shardings.  Multi-host pods are not wired.
 """
 from __future__ import annotations
 
@@ -11,6 +12,7 @@ import argparse
 import json
 
 from repro.configs import get_config, smoke_config
+from repro.launch.cache import enable_compile_cache
 from repro.train.trainer import Trainer
 
 
@@ -26,6 +28,7 @@ def main() -> None:
     ap.add_argument("--log-every", type=int, default=10)
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = smoke_config(args.arch) if args.smoke else get_config(args.arch)
     trainer = Trainer(
         cfg,
@@ -37,6 +40,7 @@ def main() -> None:
     if args.resume and trainer.resume():
         print(f"resumed from step {trainer.step}")
     out = trainer.run(args.steps, log_every=args.log_every)
+    out["mesh"] = dict(trainer.mesh.shape) if trainer.mesh is not None else None
     print(json.dumps(out, indent=1))
 
 

@@ -164,8 +164,8 @@ SHAPES: dict[str, ShapeConfig] = {
 
 
 def cell_is_supported(cfg: ArchConfig, shape: str) -> tuple[bool, str]:
-    """(supported, reason) for an (arch × shape) cell — the skip policy
-    documented in DESIGN.md §Arch-applicability."""
+    """(supported, reason) for an (arch × shape) cell: long_500k only for
+    archs with sub-quadratic decode state (ssm, hybrid)."""
     if shape == "long_500k" and not cfg.supports_long_context:
         return False, (
             "long_500k requires sub-quadratic attention; "

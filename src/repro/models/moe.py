@@ -195,12 +195,8 @@ def moe_block(
         and g % _batch_shard_count(ctx) == 0
     )
     if use_shard_map:
+        from jax import shard_map
         from jax.sharding import PartitionSpec as P
-
-        try:
-            from jax import shard_map  # jax >= 0.7 public API
-        except ImportError:  # pragma: no cover - older jax
-            from jax.experimental.shard_map import shard_map
 
         mesh = ctx.mesh
         batch_axes = tuple(a for a in ("pod", "data") if a in mesh.axis_names)

@@ -103,10 +103,13 @@ def wkv6_chunked(
         rb, kb, vb, wb = inputs                             # [B,L,H,*]
         cum = jnp.cumsum(wb, axis=1)                        # [B,L,H,K]
         cum_prev = cum - wb                                 # exclusive cumsum
-        # pairwise decay exp(cum_prev[t] - cum[j]) for j < t  (all ≤ 0)
+        # pairwise decay exp(cum_prev[t] - cum[j]) for j < t  (all ≤ 0).
+        # The masked entries (j >= t) are large and positive under strong
+        # decay; exponentiated they overflow, and the gradient through the
+        # mask becomes inf·0 = NaN, so they are set to -inf before exp.
         diff = cum_prev[:, :, None] - cum[:, None, :]       # [B,L,L,H,K]
-        dmat = jnp.where(
-            tri_strict[None, :, :, None, None], jnp.exp(diff), 0.0
+        dmat = jnp.exp(
+            jnp.where(tri_strict[None, :, :, None, None], diff, -jnp.inf)
         )
         att = jnp.einsum("blhk,bmhk,blmhk->blmh", rb, kb, dmat)
         y_intra = jnp.einsum("blmh,bmhv->blhv", att, vb)

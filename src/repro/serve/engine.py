@@ -33,7 +33,6 @@ from __future__ import annotations
 
 import threading
 import time
-import warnings
 from collections import deque
 from dataclasses import dataclass, field
 from typing import Any, Callable, Sequence
@@ -48,10 +47,6 @@ from repro.models.config import ArchConfig
 from repro.models.lm import cache_logical_specs, map_specs, zero_caches
 from repro.serve.sampling import request_key, sample_tokens
 from repro.serve.step import make_decode_step
-
-# CPU backends may decline buffer donation; the hint is still correct on
-# accelerators and the warning is noise in tests.
-warnings.filterwarnings("ignore", message=".*[Dd]onat.*")
 
 
 def cache_batch_axes(cfg: ArchConfig) -> Any:

@@ -1,8 +1,9 @@
 """Train-step factory: loss → grads → clipped AdamW → new state.
 
 The returned ``train_step(state, batch)`` is what the dry-run lowers with
-``jax.jit(..., in_shardings, out_shardings, donate_argnums=0)``; the same
-function (without a mesh) runs single-device smoke tests.
+``jax.jit(..., in_shardings, out_shardings, donate_argnums=0)``;
+``jit_train_step`` does the same for the ``Trainer`` on a real mesh, and
+without a mesh the same function runs on a single device.
 
 State layout::
 
@@ -24,6 +25,7 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import Mesh, NamedSharding
 
 from repro.models.config import ArchConfig
 from repro.models.lm import forward_train
@@ -31,6 +33,7 @@ from repro.optim.adamw import adamw_update
 from repro.optim.compress import compress_tree
 from repro.optim.schedule import cosine_with_warmup
 from repro.parallel.context import ParallelContext, activate
+from repro.parallel.sharding import spec_for, tree_shardings
 
 
 def make_train_step(
@@ -79,12 +82,50 @@ def make_train_step(
     return train_step
 
 
-def init_train_state(key: jax.Array, cfg: ArchConfig) -> dict[str, Any]:
+def jit_train_step(
+    cfg: ArchConfig,
+    *,
+    mesh: Mesh | None = None,
+    rules: Any = None,
+    batch_shape: tuple[int, int] | None = None,
+    schedule: Callable[[jnp.ndarray], jnp.ndarray] | None = None,
+) -> tuple[Callable, Any]:
+    """(jitted train step with the state donated, state shardings).
+
+    With a ``mesh`` the state is laid out by ``rules`` over its logical
+    specs, the ``batch_shape`` rows split over the batch axes, and the
+    step's in and out shardings match: the state never gathers on one
+    device.  Without one the shardings are None (single device)."""
+    step = make_train_step(cfg, mesh=mesh, rules=rules, schedule=schedule)
+    if mesh is None:
+        return jax.jit(step, donate_argnums=(0,)), None
+    sds, specs = abstract_train_state(cfg)
+    state_sh = tree_shardings(sds, specs, mesh, rules)
+    batch_sh = NamedSharding(mesh, spec_for(batch_shape, ("batch", None), mesh, rules))
+    jitted = jax.jit(
+        step,
+        in_shardings=(state_sh, batch_sh),
+        out_shardings=(state_sh, None),
+        donate_argnums=(0,),
+    )
+    return jitted, state_sh
+
+
+def init_train_state(
+    key: jax.Array, cfg: ArchConfig, *, shardings: Any = None
+) -> dict[str, Any]:
+    """Fresh state; with ``shardings`` it is built in place, shard by
+    shard, so a state larger than one device never lands on one."""
     from repro.models.lm import init_params_and_specs
     from repro.optim.adamw import init_opt_state
 
-    params, _ = init_params_and_specs(key, cfg)
-    return {"params": params, "opt": init_opt_state(params)}
+    def init(k: jax.Array) -> dict[str, Any]:
+        params, _ = init_params_and_specs(k, cfg)
+        return {"params": params, "opt": init_opt_state(params)}
+
+    if shardings is None:
+        return init(key)
+    return jax.jit(init, out_shardings=shardings)(key)
 
 
 def abstract_train_state(cfg: ArchConfig) -> tuple[dict[str, Any], dict[str, Any]]:

@@ -1,8 +1,9 @@
 """Trainer: the end-to-end driver binding data pipeline, train step,
 checkpointing, and (optionally) the orchestrator.
 
-Single-process form used by examples/tests; on a pod the same loop runs
-under ``repro.launch.train`` with the production mesh.  Fault tolerance:
+One process drives every local device: with several devices the state
+is sharded over a (data, model) mesh of all of them (``FSDP_RULES``),
+with one device the step is a plain jit.  Fault tolerance:
 async checkpoint every ``ckpt_every`` steps; ``Trainer.resume`` rebuilds
 from the latest checkpoint (used by the restart tests and by the
 orchestrator's retry path — a retried training Work resumes instead of
@@ -18,9 +19,11 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint.manager import CheckpointManager
+from repro.launch.mesh import make_host_mesh
 from repro.models.config import ArchConfig
 from repro.optim.schedule import cosine_with_warmup
-from repro.train.step import init_train_state, make_train_step
+from repro.parallel.sharding import FSDP_RULES
+from repro.train.step import init_train_state, jit_train_step
 
 
 def synthetic_batches(
@@ -66,11 +69,19 @@ class Trainer:
         schedule = cosine_with_warmup(
             cfg.max_lr, warmup_steps=max(5, total_steps // 20), total_steps=total_steps
         )
-        self.step_fn = jax.jit(
-            make_train_step(cfg, mesh=mesh, rules=rules, schedule=schedule),
-            donate_argnums=(0,),
+        # mesh=None: every device when there are several, else one device
+        if mesh is None and len(jax.devices()) > 1:
+            mesh = make_host_mesh()
+        if mesh is not None and rules is None:
+            rules = FSDP_RULES
+        self.mesh = mesh
+        self.step_fn, self.state_shardings = jit_train_step(
+            cfg, mesh=mesh, rules=rules, batch_shape=(batch_size, seq_len),
+            schedule=schedule,
         )
-        self.state = init_train_state(jax.random.PRNGKey(seed), cfg)
+        self.state = init_train_state(
+            jax.random.PRNGKey(seed), cfg, shardings=self.state_shardings
+        )
         self.ckpt = CheckpointManager(ckpt_dir) if ckpt_dir else None
         self.ckpt_every = ckpt_every
         self.step = 0
@@ -80,14 +91,17 @@ class Trainer:
         """Restore from the latest checkpoint if one exists."""
         if self.ckpt is None or self.ckpt.latest_step() is None:
             return False
-        step, self.state = self.ckpt.restore(self.state)
+        step, self.state = self.ckpt.restore(
+            self.state, shardings=self.state_shardings
+        )
         self.step = step
         return True
 
     def run(self, n_steps: int, *, log_every: int = 0) -> dict[str, Any]:
         t0 = time.time()
         for _ in range(n_steps):
-            batch = {k: jnp.asarray(v) for k, v in next(self.batch_iter).items()}
+            # host arrays: jit places them by the step's batch sharding
+            batch = {k: np.asarray(v) for k, v in next(self.batch_iter).items()}
             self.state, metrics = self.step_fn(self.state, batch)
             self.step += 1
             rec = {

@@ -65,9 +65,7 @@ def test_analytic_flops_close_to_hlo_parse_for_unrolled_model():
 
     params, _ = init_params_and_specs(jax.random.PRNGKey(0), cfg)
     compiled = jax.jit(lambda p, b: forward_train(p, b, cfg)[0]).lower(params, sds).compile()
-    from repro.common import compat
-
-    xla_flops = float(compat.cost_analysis(compiled).get("flops", 0.0))
+    xla_flops = float(compiled.cost_analysis().get("flops", 0.0))
     ours = forward_flops(cfg, shape)
     # loss adds a vocab matmul per chunk; attention scans count once in XLA.
     # The analytic forward count must be within 2x of XLA's (sanity band).
