@@ -86,35 +86,25 @@ class Phases:
     """Runs phases in order and prints one line each.
 
     Compile time is the sum of JAX's backend-compile durations inside the
-    phase (a persistent-cache hit counts its load time)."""
-
-    def __init__(self) -> None:
-        import jax
-
-        self.compile_s = 0.0
-        self.hits = 0
-        self.misses = 0
-        jax.monitoring.register_event_duration_secs_listener(self._on_duration)
-        jax.monitoring.register_event_listener(self._on_event)
-
-    def _on_duration(self, event: str, duration: float, **_: object) -> None:
-        if event == "/jax/core/compile/backend_compile_duration":
-            self.compile_s += duration
-
-    def _on_event(self, event: str, **_: object) -> None:
-        if event == "/jax/compilation_cache/cache_hits":
-            self.hits += 1
-        elif event == "/jax/compilation_cache/cache_misses":
-            self.misses += 1
+    phase (a persistent-cache hit counts its load time); it, the cache's
+    hits and misses, and the train steps and tokens are the deltas of the
+    program tracer's counters (``repro.monitor.trace``)."""
 
     def run(self, name: str, fn) -> object:
-        c0, h0, m0 = self.compile_s, self.hits, self.misses
+        from repro.monitor import trace
+
+        c0 = trace.counters()
         t0 = time.perf_counter()
         out = fn()
         wall = time.perf_counter() - t0
+        c1 = trace.counters()
+        d = {k: c1.get(k, 0) - c0.get(k, 0) for k in c1}
+        trained = (f" train_steps={d['train.steps']:.0f} train_tokens={d['train.tokens']:.0f}"
+                   if d.get("train.steps") else "")
         print(
-            f"[phase] {name}: ok wall_s={wall:.1f} compile_s={self.compile_s - c0:.1f} "
-            f"cache_hits={self.hits - h0} cache_misses={self.misses - m0} | {out}",
+            f"[phase] {name}: ok wall_s={wall:.1f} compile_s={d.get('compile.s', 0):.1f} "
+            f"cache_hits={d.get('compile.cache_hits', 0):.0f} "
+            f"cache_misses={d.get('compile.cache_misses', 0):.0f}{trained} | {out}",
             flush=True,
         )
         return out

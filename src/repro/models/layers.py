@@ -28,6 +28,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
+from repro.monitor.trace import scope
+
 Params = dict[str, Any]
 Specs = dict[str, Any]
 
@@ -80,6 +82,7 @@ def split_tree(tree: Any) -> tuple[Any, Any]:
 # ---------------------------------------------------------------------------
 # norms & rotary embeddings
 # ---------------------------------------------------------------------------
+@scope("norm")
 def rms_norm(x: jnp.ndarray, weight: jnp.ndarray, eps: float = 1e-6) -> jnp.ndarray:
     dtype = x.dtype
     x32 = x.astype(jnp.float32)
@@ -300,6 +303,7 @@ def init_attention(key: jax.Array, cfg: Any, dtype: Any) -> Params:
     return p
 
 
+@scope("attention")
 def attention_block(
     params: Params,
     x: jnp.ndarray,
@@ -372,6 +376,7 @@ def init_mlp(key: jax.Array, d_model: int, d_ff: int, dtype: Any) -> Params:
     }
 
 
+@scope("mlp")
 def mlp_block(params: Params, x: jnp.ndarray) -> jnp.ndarray:
     h = jax.nn.silu(x @ params["w_gate"]) * (x @ params["w_up"])
     return h @ params["w_down"]

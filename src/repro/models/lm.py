@@ -48,6 +48,7 @@ from repro.models.rwkv import (
     rwkv6_time_mix,
 )
 from repro.models.ssm import d_inner, init_mamba2, mamba2_block, n_ssm_heads
+from repro.monitor.trace import scope
 from repro.parallel.context import constrain_residual
 
 
@@ -245,6 +246,7 @@ def abstract_params(cfg: ArchConfig) -> tuple[Any, Any]:
 # ---------------------------------------------------------------------------
 # embedding / head
 # ---------------------------------------------------------------------------
+@scope("embed")
 def embed_tokens(params: Any, tokens: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     return jnp.take(params["embed"], tokens, axis=0)
 
@@ -259,11 +261,13 @@ def splice_patches(
     return lax.dynamic_update_slice_in_dim(x, proj.astype(x.dtype), 0, axis=1)
 
 
+@scope("head_loss")
 def lm_logits(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> jnp.ndarray:
     w = params["embed"].T if cfg.tie_embeddings else params["unembed"]
     return x @ w
 
 
+@scope("head_loss")
 def chunked_ce_loss(
     params: Any,
     x: jnp.ndarray,
@@ -379,6 +383,10 @@ def _named(x: jnp.ndarray, name: str, cfg: ArchConfig) -> jnp.ndarray:
 # ---------------------------------------------------------------------------
 # trunk forward (train / prefill share this)
 # ---------------------------------------------------------------------------
+#: ``lax.scan`` over the trunk's layers, under the ``layers`` scope
+_scan_layers = scope("layers")(lax.scan)
+
+
 def forward_trunk(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Run all layers; returns (hidden, aux_loss)."""
     b, s, _ = x.shape
@@ -388,7 +396,7 @@ def forward_trunk(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> tuple[jnp.nda
     fam = cfg.family
     if fam in ("dense", "vlm", "audio") and not cfg.local_global_pattern:
         body = _maybe_remat(_dense_body(cfg, positions, 0, impl), cfg)
-        x, _ = lax.scan(body, x, params["layers"])
+        x, _ = _scan_layers(body, x, params["layers"])
     elif fam == "dense" and cfg.local_global_pattern:
         local_body = _maybe_remat(
             _dense_body(cfg, positions, cfg.sliding_window, impl), cfg
@@ -396,20 +404,20 @@ def forward_trunk(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> tuple[jnp.nda
         global_body = _maybe_remat(_dense_body(cfg, positions, 0, impl), cfg)
 
         def super_body(xc, lp):
-            xc, _ = lax.scan(local_body, xc, lp["local"])
+            xc, _ = _scan_layers(local_body, xc, lp["local"])
             xc, _ = global_body(xc, lp["global"])
             return xc, None
 
         stacked = {"local": params["local_layers"], "global": params["global_layers"]}
-        x, _ = lax.scan(super_body, x, stacked)
+        x, _ = _scan_layers(super_body, x, stacked)
         if "tail_layers" in params:
-            x, _ = lax.scan(local_body, x, params["tail_layers"])
+            x, _ = _scan_layers(local_body, x, params["tail_layers"])
     elif fam == "moe":
         body = _maybe_remat(_moe_body(cfg, positions, impl), cfg)
-        (x, aux), _ = lax.scan(body, (x, aux), params["layers"])
+        (x, aux), _ = _scan_layers(body, (x, aux), params["layers"])
     elif fam == "ssm":
         body = _maybe_remat(_rwkv_body(cfg), cfg)
-        x, _ = lax.scan(body, x, params["layers"])
+        x, _ = _scan_layers(body, x, params["layers"])
     elif fam == "hybrid":
         mamba_body = _maybe_remat(_mamba_body(cfg), cfg)
         attn_body = _maybe_remat(
@@ -417,13 +425,13 @@ def forward_trunk(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> tuple[jnp.nda
         )
 
         def super_body(xc, lp):
-            xc, _ = lax.scan(mamba_body, xc, lp)
+            xc, _ = _scan_layers(mamba_body, xc, lp)
             xc, _ = attn_body(xc, params["shared_attn"])  # shared weights
             return xc, None
 
-        x, _ = lax.scan(super_body, x, params["mamba_layers"])
+        x, _ = _scan_layers(super_body, x, params["mamba_layers"])
         if "tail_layers" in params:
-            x, _ = lax.scan(mamba_body, x, params["tail_layers"])
+            x, _ = _scan_layers(mamba_body, x, params["tail_layers"])
     else:
         raise ValueError(fam)
     return rms_norm(x, params["final_norm"], cfg.norm_eps), aux

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.models.layers import Params, dense_init
+from repro.monitor.trace import scope
 from repro.parallel import context as pctx
 
 # ---------------------------------------------------------------------------
@@ -171,6 +172,7 @@ def _routed_group(
     return y, me_sum, ce_sum
 
 
+@scope("moe")
 def moe_block(
     params: Params, x: jnp.ndarray, cfg: Any
 ) -> tuple[jnp.ndarray, jnp.ndarray]:

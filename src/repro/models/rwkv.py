@@ -22,6 +22,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.layers import Params, dense_init, ones_init, rms_norm, zeros_init
+from repro.monitor.trace import scope
 
 
 def n_rwkv_heads(cfg: Any) -> int:
@@ -165,6 +166,7 @@ def _token_shift(x: jnp.ndarray, last: jnp.ndarray | None) -> jnp.ndarray:
     return jnp.concatenate([last.astype(x.dtype), x], axis=1)[:, :-1]
 
 
+@scope("time_mix")
 def rwkv6_time_mix(
     params: Params,
     x: jnp.ndarray,
@@ -202,6 +204,7 @@ def rwkv6_time_mix(
     return out, (new_state, x[:, -1:, :])
 
 
+@scope("channel_mix")
 def rwkv6_channel_mix(
     params: Params,
     x: jnp.ndarray,

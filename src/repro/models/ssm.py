@@ -16,6 +16,7 @@ import jax.numpy as jnp
 from jax import lax
 
 from repro.models.layers import Params, dense_init, ones_init, rms_norm, zeros_init
+from repro.monitor.trace import scope
 
 
 def d_inner(cfg: Any) -> int:
@@ -153,6 +154,7 @@ def ssd_decode_step(
     return y[:, None].astype(x.dtype), new_state
 
 
+@scope("mamba")
 def mamba2_block(
     params: Params,
     x: jnp.ndarray,

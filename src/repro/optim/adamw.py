@@ -14,6 +14,8 @@ from typing import Any, Callable
 import jax
 import jax.numpy as jnp
 
+from repro.monitor.trace import scope
+
 
 def init_opt_state(params: Any) -> dict[str, Any]:
     # copy=True: fp32 params must not ALIAS the master copy (donation!)
@@ -44,6 +46,7 @@ def global_norm(tree: Any) -> jnp.ndarray:
     )
 
 
+@scope("optimizer")
 def adamw_update(
     grads: Any,
     opt: dict[str, Any],

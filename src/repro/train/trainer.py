@@ -21,6 +21,7 @@ import numpy as np
 from repro.checkpoint.manager import CheckpointManager
 from repro.launch.mesh import make_host_mesh
 from repro.models.config import ArchConfig
+from repro.monitor import trace
 from repro.optim.schedule import cosine_with_warmup
 from repro.parallel.sharding import FSDP_RULES
 from repro.train.step import init_train_state, jit_train_step
@@ -98,17 +99,27 @@ class Trainer:
         return True
 
     def run(self, n_steps: int, *, log_every: int = 0) -> dict[str, Any]:
-        t0 = time.time()
+        """``n_steps`` steps, each in three spans of the program's tracer
+        (``train.batch``, ``train.dispatch``, ``train.sync``, with the step
+        number) and ``train.checkpoint`` where a save happens."""
+        tokens = self.batch_size * self.seq_len
+        t0 = time.perf_counter()
         for _ in range(n_steps):
-            # host arrays: jit places them by the step's batch sharding
-            batch = {k: np.asarray(v) for k, v in next(self.batch_iter).items()}
-            self.state, metrics = self.step_fn(self.state, batch)
-            self.step += 1
-            rec = {
-                "step": self.step,
-                "loss": float(metrics["loss"]),
-                "grad_norm": float(metrics["grad_norm"]),
-            }
+            step = self.step + 1
+            with trace.span("train.batch", step=step):
+                # host arrays: jit places them by the step's batch sharding
+                batch = {k: np.asarray(v) for k, v in next(self.batch_iter).items()}
+            with trace.span("train.dispatch", step=step):
+                self.state, metrics = self.step_fn(self.state, batch)
+            with trace.span("train.sync", step=step):
+                rec = {
+                    "step": step,
+                    "loss": float(metrics["loss"]),
+                    "grad_norm": float(metrics["grad_norm"]),
+                }
+            self.step = step
+            trace.count("train.steps")
+            trace.count("train.tokens", tokens)
             self.history.append(rec)
             if log_every and self.step % log_every == 0:
                 print(
@@ -117,16 +128,18 @@ class Trainer:
                     flush=True,
                 )
             if self.ckpt is not None and self.step % self.ckpt_every == 0:
-                self.ckpt.save(self.step, self.state)
+                with trace.span("train.checkpoint", step=step):
+                    self.ckpt.save(self.step, self.state)
         if self.ckpt is not None:
-            self.ckpt.save(self.step, self.state, blocking=True)
+            with trace.span("train.checkpoint", step=self.step):
+                self.ckpt.save(self.step, self.state, blocking=True)
+        wall = time.perf_counter() - t0
         return {
             "final_loss": self.history[-1]["loss"] if self.history else None,
             "initial_loss": self.history[0]["loss"] if self.history else None,
             "steps": self.step,
-            "wall_s": time.time() - t0,
-            "tokens_per_s": n_steps * self.batch_size * self.seq_len
-            / max(time.time() - t0, 1e-9),
+            "wall_s": wall,
+            "tokens_per_s": n_steps * tokens / max(wall, 1e-9),
         }
 
 
