@@ -87,8 +87,9 @@ class Phases:
 
     Compile time is the sum of JAX's backend-compile durations inside the
     phase (a persistent-cache hit counts its load time); it, the cache's
-    hits and misses, and the train steps and tokens are the deltas of the
-    program tracer's counters (``repro.monitor.trace``)."""
+    hits and misses, the train steps and tokens, and the layer bodies traced
+    with each attention path (``attention_flash``, ``attention_chunked``)
+    are the deltas of the program tracer's counters (``repro.monitor.trace``)."""
 
     def run(self, name: str, fn) -> object:
         from repro.monitor import trace
@@ -100,6 +101,8 @@ class Phases:
         c1 = trace.counters()
         d = {k: c1.get(k, 0) - c0.get(k, 0) for k in c1}
         trained = (f" train_steps={d['train.steps']:.0f} train_tokens={d['train.tokens']:.0f}"
+                   f" attention_flash={d.get('attention.flash', 0):.0f}"
+                   f" attention_chunked={d.get('attention.chunked', 0):.0f}"
                    if d.get("train.steps") else "")
         print(
             f"[phase] {name}: ok wall_s={wall:.1f} compile_s={d.get('compile.s', 0):.1f} "
@@ -186,7 +189,8 @@ def logits_error(params, cfg, tokens, device) -> tuple[float, float]:
     with jax.default_device(device):
         got = np.asarray(jax.jit(lambda p, t: last_logits(p, t, cfg))(params, tokens))
     cpu = jax.devices("cpu")[0]
-    cfg32 = cfg.replace(dtype="float32")
+    # the path follows the default backend, the TPU: name the CPU's own
+    cfg32 = cfg.replace(dtype="float32", attention_impl="chunked")
     p32 = jax.device_put(jax.tree.map(lambda x: np.asarray(x, np.float32), params), cpu)
     with jax.default_device(cpu), jax.default_matmul_precision("highest"):
         ref = np.asarray(jax.jit(lambda p, t: last_logits(p, t, cfg32))(p32, tokens))

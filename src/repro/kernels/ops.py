@@ -32,8 +32,8 @@ def attention(
     causal: bool = True,
     window: int = 0,
     impl: str = "reference",
-    block_q: int = 256,
-    block_kv: int = 256,
+    block_q: int | None = None,
+    block_kv: int | None = None,
 ) -> jnp.ndarray:
     if impl == "pallas":
         return flash_attention_pallas(

@@ -64,7 +64,9 @@ class ArchConfig:
     norm_eps: float = 1e-6
     remat: str = "full"         # none | full  (activation checkpoint policy)
     scan_layers: bool = True
-    attention_impl: str = "reference"  # reference | pallas
+    # reference: the flash kernel on a TPU, else the chunked scan (lm.attention_path);
+    # or an attention_block impl by name (pallas | interpret | chunked | naive)
+    attention_impl: str = "reference"
     # training bits
     max_lr: float = 3e-4
 

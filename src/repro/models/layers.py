@@ -7,16 +7,20 @@ maps logical axes (``"embed"``, ``"heads"``, ``"mlp"``, ``"experts"``,
 ``"vocab"``, ...) onto mesh axes per architecture — the MaxText/t5x
 pattern.
 
-Attention reference implementations:
+Attention.  Without a KV cache (train, prefill) ``attention_block`` runs
+the Pallas flash kernel (``repro.kernels.flash_attention``, with its own
+backward) on a TPU, and on the CPU the chunked scan below
+(``models.lm.attention_path`` chooses).  The jnp implementations:
 
 * ``attention_naive``    — full score matrix; test oracle only.
 * ``attention_chunked``  — online-softmax over KV chunks (the flash
-  recurrence in lax ops); O(S·chunk) memory, compiles for 32k+ sequences.
-  This is the mathematical spec the Pallas kernel implements.
+  recurrence in lax ops): the CPU path and the kernel's spec.  It computes
+  the full causal rectangle, and autodiff keeps every chunk's scores.
 * ``attention_windowed`` — sliding-window attention scanning query chunks
-  against a dynamic KV band; FLOPs ∝ S·(window+chunk), used by gemma3's
-  local layers.
-* ``attention_decode``   — single-token decode against a KV cache.
+  against a dynamic KV band; FLOPs ∝ S·(window+chunk), gemma3's local
+  layers on the CPU.
+* ``attention_decode``   — single-token decode against a KV cache, on every
+  backend.
 """
 from __future__ import annotations
 
@@ -28,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.monitor.trace import scope
+from repro.monitor.trace import count, scope
 
 Params = dict[str, Any]
 Specs = dict[str, Any]
@@ -317,7 +321,11 @@ def attention_block(
 ) -> tuple[jnp.ndarray, tuple[jnp.ndarray, jnp.ndarray] | None]:
     """Self-attention block; returns (out, updated_cache).
 
-    Training/prefill: kv_cache=None → causal self-attention over x.
+    Training/prefill: kv_cache=None → causal self-attention over x, by
+    ``impl``: ``"pallas"`` or ``"interpret"`` the flash kernel, ``"chunked"``
+    the chunked (or windowed) scan, ``"naive"`` the full matrix.  Tracing the
+    first two counts ``attention.flash``, the chunked scan
+    ``attention.chunked`` (``repro.monitor.trace.count``).
     Decode: kv_cache=(k,v) preallocated [B,Smax,Hkv,D]; x is one token and
     cache_length its position; new K/V are written at that position.
     """
@@ -350,13 +358,16 @@ def attention_block(
     elif impl in ("pallas", "interpret"):
         from repro.kernels.flash_attention import flash_attention_pallas
 
+        count("attention.flash")
         out = flash_attention_pallas(
             q, k, v, causal=True, window=int(window),
             interpret=(impl == "interpret"),
         )
     elif window and impl != "naive":
+        count("attention.chunked")
         out = attention_windowed(q, k, v, window=window)
     elif impl == "chunked":
+        count("attention.chunked")
         out = attention_chunked(q, k, v, causal=True, window=window)
     else:
         out = attention_naive(q, k, v, causal=True, window=window)

@@ -49,7 +49,7 @@ from repro.models.rwkv import (
 )
 from repro.models.ssm import d_inner, init_mamba2, mamba2_block, n_ssm_heads
 from repro.monitor.trace import scope
-from repro.parallel.context import constrain_residual
+from repro.parallel.context import constrain_residual, current
 
 
 # ---------------------------------------------------------------------------
@@ -307,6 +307,19 @@ def chunked_ce_loss(
     return tot / jnp.maximum(cnt, 1.0)
 
 
+def attention_path(cfg: ArchConfig) -> str:
+    """The path of attention without a KV cache (``attention_block``'s ``impl``).
+
+    ``attention_impl="reference"`` resolves to the flash kernel on a TPU and
+    to the chunked scan elsewhere.  Under a multi-device mesh it stays on the
+    chunked scan: the partitioner cannot split a kernel's custom call."""
+    if cfg.attention_impl != "reference":
+        return cfg.attention_impl
+    ctx = current()
+    one_device = ctx is None or ctx.mesh.size == 1
+    return "pallas" if jax.default_backend() == "tpu" and one_device else "chunked"
+
+
 # ---------------------------------------------------------------------------
 # layer bodies (shared by train/prefill; decode variants below)
 # ---------------------------------------------------------------------------
@@ -391,7 +404,7 @@ def forward_trunk(params: Any, x: jnp.ndarray, cfg: ArchConfig) -> tuple[jnp.nda
     """Run all layers; returns (hidden, aux_loss)."""
     b, s, _ = x.shape
     positions = jnp.arange(s, dtype=jnp.int32)
-    impl = "chunked" if cfg.attention_impl == "reference" else cfg.attention_impl
+    impl = attention_path(cfg)
     aux = jnp.zeros((), jnp.float32)
     fam = cfg.family
     if fam in ("dense", "vlm", "audio") and not cfg.local_global_pattern:
@@ -642,7 +655,7 @@ def forward_decode(
     else:
         x = embed_tokens(params, batch["token"], cfg)
     positions = position + jnp.zeros((1,), jnp.int32)
-    impl = "chunked" if cfg.attention_impl == "reference" else cfg.attention_impl
+    impl = attention_path(cfg)
     fam = cfg.family
     new_caches: dict[str, Any] = {}
 

@@ -28,11 +28,25 @@ def _attn(b, s, hq, hkv, d):
     return [((b, s, hq, d), BF16), ((b, s, hkv, d), BF16), ((b, s, hkv, d), BF16)]
 
 
+def _flash_grad(window=0):
+    """dQ, dK, dV through the kernel's own backward: the forward that also
+    writes the log-sum-exp, then the dK/dV and dQ kernels."""
+    def loss(q, k, v):
+        return jnp.sum(flash_attention_pallas(q, k, v, window=window).astype(F32))
+
+    return jax.grad(loss, argnums=(0, 1, 2))
+
+
 # (kernel, [(shape, dtype) per argument]) at each model's published width
 CASES = {
     "flash_attention-qwen3-4b": (flash_attention_pallas, _attn(1, 2048, 32, 8, 128)),
     "flash_attention-smollm-360m": (flash_attention_pallas, _attn(1, 2048, 15, 5, 64)),
     "flash_attention-smollm-360m-s100": (flash_attention_pallas, _attn(1, 100, 15, 5, 64)),
+    "flash_attention_grad-qwen3-4b": (_flash_grad(), _attn(1, 2048, 32, 8, 128)),
+    "flash_attention_grad-smollm-360m-train": (_flash_grad(), _attn(8, 2048, 15, 5, 64)),
+    "flash_attention_grad-smollm-360m-s100": (_flash_grad(), _attn(1, 100, 15, 5, 64)),
+    "flash_attention_grad-gemma3-4b-local": (_flash_grad(1024), _attn(1, 2048, 8, 4, 256)),
+    "flash_attention_grad-olmoe-1b-7b": (_flash_grad(), _attn(1, 2048, 16, 16, 128)),
     "wkv6-rwkv6-1.6b": (
         wkv6_pallas,
         [((1, 2048, 32, 64), BF16)] * 4 + [((32, 64), F32)],
@@ -76,4 +90,5 @@ def test_kernel_compiles_for_v5e(one_chip, case):
     kernel, args = CASES[case]
     sds = [jax.ShapeDtypeStruct(shape, dt, sharding=one_chip) for shape, dt in args]
     compiled = jax.jit(kernel).lower(*sds).compile()
-    assert "tpu_custom_call" in compiled.as_text()
+    kernels = 3 if case.startswith("flash_attention_grad") else 1
+    assert compiled.as_text().count('custom_call_target="tpu_custom_call"') >= kernels

@@ -231,3 +231,5 @@ def test_chip_smoke_phase_line_reads_the_tracers_counters(capsys):
     phases.run("train", lambda: trainer.run(2)["steps"])
     fields = dict(kv.split("=") for kv in capsys.readouterr().out.split(" | ")[0].split()[3:])
     assert fields["train_steps"] == "2" and fields["train_tokens"] == str(2 * 2 * 16)
+    # on the CPU the step is traced with the chunked scan, never the kernel
+    assert fields["attention_flash"] == "0" and int(fields["attention_chunked"]) >= 1
