@@ -14,8 +14,9 @@ in a file of its own under ``bench/`` and is found here by the name that
 
 ``bench/planned_cells.json`` lists cells whose files are here and tested on
 the CPU but which are not measured yet: ``calibrate.py`` and ``sweep.py``
-take them, ``run.py`` does not.  A benchmark PR that measures one moves its
-entry into ``BENCHMARK.json`` and writes its limits file from the readings.
+take them, ``run.py`` does not.  A cell is planned only while
+``BENCHMARK.json`` lacks it: a PR that measures one adds its entry there
+and its limits file, and touches no file the benchmark already has.
 """
 from __future__ import annotations
 
@@ -86,10 +87,17 @@ def _applies(metric: dict[str, Any], cell: str, reported: set[str]) -> bool:
     return metric.get("moves", metric["name"]) in reported
 
 
+def planned(bench: dict[str, Any] | None = None) -> list[dict[str, Any]]:
+    """The entries of ``planned_cells.json`` that ``BENCHMARK.json`` lacks."""
+    bench = bench if bench is not None else load_json(ROOT / "BENCHMARK.json")
+    gated = {w["name"] for w in bench["workloads"]}
+    return [w for w in load_json(BENCH / "planned_cells.json") if w["name"] not in gated]
+
+
 def with_planned() -> dict[str, Any]:
-    """``BENCHMARK.json`` with the planned cells among its workloads."""
+    """``BENCHMARK.json`` with the still-planned cells among its workloads."""
     bench = load_json(ROOT / "BENCHMARK.json")
-    bench["workloads"] = bench["workloads"] + load_json(BENCH / "planned_cells.json")
+    bench["workloads"] = bench["workloads"] + planned(bench)
     return bench
 
 

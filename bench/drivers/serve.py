@@ -78,13 +78,17 @@ def _tokens(rng, n: int, vocab: int) -> list[int]:
 
 def closed_work(t: dict, seed: int, index: int, vocab: int) -> WorkSpec:
     """Work ``index`` of a closed loop: ``prompts_per_work`` prompts whose
-    lengths are the same strata in every Work, shuffled; the output length
-    cycles through ``max_new_tokens`` in a seeded order per block."""
+    lengths are the same strata in every Work, shuffled in an order that the
+    Work's index fixes and the seed does not (the engine prefills them in
+    groups padded to each group's longest, so an order from the seed changed
+    the padded prefill work, and the rate, by up to 10% from seed to seed);
+    the seed draws the tokens, and the output length cycles through
+    ``max_new_tokens`` in a seeded order per block."""
     rng = common.np_rng(seed, index)
     lengths = common.lognormal_quantiles(
         t["prompts_per_work"], t["prompt_median"], t["prompt_sigma"],
         t["prompt_min"], t["prompt_max"])
-    lengths = rng.permutation(lengths)
+    lengths = common.np_rng(0, index).permutation(lengths)
     cycle = t["max_new_tokens"]
     block = common.np_rng(seed, 10**6 + index // len(cycle)).permutation(cycle)
     return WorkSpec(index, [_tokens(rng, int(n), vocab) for n in lengths],

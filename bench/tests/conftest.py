@@ -2,9 +2,19 @@
 
     JAX_PLATFORMS=cpu python -m pytest bench/tests
 
-The cells keep their traffic's shape and their limits; only the model's
-sizes shrink (``TINY``), with the engine and prompt lengths to fit.  The
-planned cells, not measured yet, run with ``TINY_LIMITS``.
+The cells keep their traffic's shape and their limits; only the sizes
+shrink, from one file per name under ``bench/tests/tiny/``:
+
+* ``tiny/configs/<config>.json``  the model's sizes, over the config's file;
+* ``tiny/traffic/<traffic>.json`` the mix's overrides (engine and prompt
+  lengths to fit the tiny model);
+* ``tiny/limits/<driver>.json``   the limits a planned cell runs with until
+  its own limits file is written from chip readings: at tiny sizes only,
+  from CPU runs of the program and the faults.  Their keys are the limits
+  the driver reads.
+
+A new configuration or traffic mix adds its own file there, beside its
+``bench/configs`` or ``bench/traffic`` file; no table here names it.
 """
 from __future__ import annotations
 
@@ -16,55 +26,43 @@ import pytest
 
 ROOT = pathlib.Path(__file__).resolve().parents[2]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
-
-TINY = {
-    "smollm-360m": dict(hidden_size=64, intermediate_size=128, num_attention_heads=4,
-                        num_key_value_heads=2, head_dim=16, num_hidden_layers=2,
-                        vocab_size=2048),
-    "rwkv6-1.6b": dict(hidden_size=64, num_hidden_layers=2, head_size=16,
-                       intermediate_size=128, vocab_size=2048),
-}
-TRAFFIC = {
-    "batch_serve": dict(prompt_max=64, prompt_median=24, prompts_per_work=8,
-                        max_new_tokens=[8, 16, 24, 32],
-                        engine={"n_slots": 8, "prefill_batch": 4, "max_seq": 160}),
-    "work_stream": dict(rate_per_s=4.0),
-    "train": dict(batch_size=4, seq_len=64),
-    "train_4chip": dict(batch_size=4, seq_len=64),
-}
+TINY = pathlib.Path(__file__).resolve().parent / "tiny"
 
 
-#: limits for the planned cells (``bench/planned_cells.json``), which have
-#: no limits file until they are measured on the chip: at these tiny sizes
-#: only, from CPU runs of the program and the faults
-TINY_LIMITS = {
-    "serve": {"served_gap": 0.15},
-    "train": {"loss_gap": 0.001, "grad_gap": 0.03, "grad_raw_gap": 0.1, "update_gap": 0.1},
-}
+def tiny(kind: str, name: str) -> dict:
+    """``tiny/<kind>/<name>.json``; a missing file names the file to add."""
+    from bench.common import BenchError, load_json
+
+    path = TINY / kind / f"{name}.json"
+    if not path.is_file():
+        raise BenchError(f"no tiny {kind} for {name!r}: add {path.relative_to(ROOT)}")
+    return load_json(path)
 
 
 def planned_names() -> set:
-    from bench.common import BENCH, load_json
+    from bench.common import planned
 
-    return {w["name"] for w in load_json(BENCH / "planned_cells.json")}
+    return {w["name"] for w in planned()}
 
 
 def tiny_cell(name: str):
     from bench.common import load_json, resolve_cell, with_planned
 
     bench = with_planned()
-    traffic = next(w["traffic"] for w in bench["workloads"] if w["name"] == name)
-    driver = load_json(ROOT / "bench" / "traffic" / f"{traffic}.json")["driver"]
-    limits = TINY_LIMITS[driver] if name in planned_names() else None
+    w = next(w for w in bench["workloads"] if w["name"] == name)
+    driver = load_json(ROOT / "bench" / "traffic" / f"{w['traffic']}.json")["driver"]
+    limits = tiny("limits", driver) if name in planned_names() else None
     cell = resolve_cell(name, bench, limits=limits)
-    cell.config = {**cell.config, **TINY[cell.config["arch"]]}
-    cell.traffic = {**cell.traffic, **TRAFFIC[traffic]}
+    cell.config = {**cell.config, **tiny("configs", w["config"])}
+    cell.traffic = {**cell.traffic, **tiny("traffic", w["traffic"])}
     cell.chips = 1
     return cell
 
 
-def run_tiny(name: str, seconds: float = 2.0, trace: int = 0, seed: int = 2**32 + 5) -> dict:
-    """One run of the harness past its look for a chip, on the CPU."""
+def run_tiny(name: str, seconds: float = 2.0, trace: int = 0, seed: int = 2**32 + 5,
+             cell=None) -> dict:
+    """One run of the harness past its look for a chip, on the CPU, of the
+    tiny cell ``name`` or of ``cell`` as given."""
     import jax
 
     from bench import peaks, run
@@ -72,7 +70,7 @@ def run_tiny(name: str, seconds: float = 2.0, trace: int = 0, seed: int = 2**32 
     kind = jax.devices()[0].device_kind
     peaks.PEAKS.setdefault(kind, dict(peaks.PEAKS["TPU v5 lite"]))
     args = argparse.Namespace(workload=name, seed=seed, seconds=seconds, trace=trace)
-    return run.run(args, jax.devices(), trace_seconds=0.5, cell=tiny_cell(name))
+    return run.run(args, jax.devices(), trace_seconds=0.5, cell=cell or tiny_cell(name))
 
 
 @pytest.fixture(autouse=True)

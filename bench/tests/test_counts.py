@@ -6,6 +6,7 @@ import pytest
 
 from conftest import ROOT
 
+CONFIGS = sorted(p.stem for p in (ROOT / "bench" / "configs").glob("*.json"))
 SMALL_LLAMA = {
     "num_hidden_layers": 1, "hidden_size": 4, "num_attention_heads": 2,
     "num_key_value_heads": 1, "head_dim": 2, "intermediate_size": 8, "vocab_size": 16,
@@ -13,7 +14,7 @@ SMALL_LLAMA = {
 }
 
 
-@pytest.mark.parametrize("name", ["smollm-360m", "rwkv6-1.6b"])
+@pytest.mark.parametrize("name", CONFIGS)
 def test_param_count_matches_program(name):
     from bench.common import BENCH, load_json, load_module
     from repro.launch.analytic import exact_param_counts

@@ -51,15 +51,39 @@ def test_config_file_is_the_run(config):
 
 def test_planned_cells_resolve_apart():
     """Planned cells have their files but no limits, and stay out of
-    BENCHMARK.json until they are measured."""
-    from bench.common import BENCH as DIR, load_json, resolve_cell, with_planned
+    BENCHMARK.json until they are measured; a cell is planned only while
+    BENCHMARK.json lacks it."""
+    from bench.common import BENCH as DIR, planned, resolve_cell, with_planned
 
-    planned = load_json(DIR / "planned_cells.json")
-    assert not {w["name"] for w in planned} & set(CELLS)
-    for w in planned:
+    still = planned()
+    assert not {w["name"] for w in still} & set(CELLS)
+    for w in still:
         assert not (DIR / "limits" / f"{w['name']}.json").exists()
         cell = resolve_cell(w["name"], with_planned(), limits={})
         assert cell.driver.setup and cell.reference.make_weights
+
+
+@pytest.mark.parametrize("name", CELLS)
+def test_limits_file_is_what_its_driver_reads(name):
+    """Every cell of BENCHMARK.json has its limits file, holding exactly the
+    limits its driver reads (the keys of ``tiny/limits/<driver>.json``)."""
+    from conftest import tiny
+
+    from bench.common import BENCH as DIR, load_json
+
+    w = next(w for w in BENCH["workloads"] if w["name"] == name)
+    path = DIR / "limits" / f"{name}.json"
+    assert path.is_file(), f"add {path.relative_to(ROOT)}"
+    driver = load_json(DIR / "traffic" / f"{w['traffic']}.json")["driver"]
+    assert set(load_json(path)) == set(tiny("limits", driver))
+
+
+def test_with_planned_names_each_cell_once():
+    from bench.common import with_planned
+
+    names = [w["name"] for w in with_planned()["workloads"]]
+    assert len(names) == len(set(names))
+    assert names[: len(CELLS)] == CELLS
 
 
 def test_missing_file_is_an_error(tmp_path):

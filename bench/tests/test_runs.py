@@ -33,6 +33,20 @@ def test_sound_run_is_correct(name):
                                         if "workloads" not in m or name in m["workloads"]}
 
 
+@pytest.mark.parametrize("driver", sorted(set(DRIVER.values())))
+def test_run_holds_each_limit_its_driver_reads(driver):
+    """Every limit in ``tiny/limits/<driver>.json`` comes back in the run's
+    checks beside its number; a check the limits do not set is exact."""
+    from conftest import tiny
+
+    name = next(n for n in CELLS if DRIVER[n] == driver)
+    cell = tiny_cell(name)
+    cell.limits = {k: 1e6 + i for i, k in enumerate(tiny("limits", driver))}
+    checks = run_tiny(name, cell=cell)["checks"]
+    assert {k: checks[k]["limit"] for k in cell.limits if k in checks} == cell.limits
+    assert all(c["limit"] == 0.0 for k, c in checks.items() if k not in cell.limits)
+
+
 @pytest.mark.parametrize("name", SERVE)
 def test_token_altered_is_not_correct(name, monkeypatch):
     from repro.serve import engine
